@@ -89,6 +89,7 @@ from repro.core.engine import BACKENDS, DuDeEngine
 from repro.core.flatten import make_flat_spec
 from repro.kernels import ref
 from repro.kernels.ops import dude_update, flash_attention, flash_decode
+from repro.launch.mesh import make_mesh
 from repro.optim import flat_adamw, flat_momentum_sgd, flat_sgd
 from repro.sharding import flat_train_state_shardings
 
@@ -133,7 +134,7 @@ def engine_sweep(backends=BACKENDS, points=ENGINE_POINTS,
         if ndev < 2:
             raise ValueError("sharded sweep needs >1 device "
                              "(set --xla_force_host_platform_device_count)")
-        mesh = jax.make_mesh((ndev,), ("p",))
+        mesh = make_mesh((ndev,), ("p",))
     rows = []
     key = jax.random.PRNGKey(42)
     for n, P in points:
@@ -206,7 +207,7 @@ def round_apply_sweep(backends=BACKENDS, opts=tuple(FLAT_OPTS),
         ndev = jax.device_count()
         if ndev < 2:
             raise ValueError("sharded sweep needs >1 device")
-        mesh = jax.make_mesh((ndev,), ("p",))
+        mesh = make_mesh((ndev,), ("p",))
     n, P = point
     rows = []
     key = jax.random.PRNGKey(7)
@@ -766,8 +767,7 @@ def unravel_sweep(arch: str = "qwen2_0_5b", shape=(2, 4),
     if jax.device_count() < d * m:
         print(f"# unravel sweep skipped: needs {d * m} devices")
         return []
-    mesh = jax.sharding.Mesh(
-        np.asarray(jax.devices()[: d * m]).reshape(d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"), devices=jax.devices()[: d * m])
     axes = ("data", "model")
     cfg = get_config(arch).smoke()
     n = n_workers or cfg.n_workers

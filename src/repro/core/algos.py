@@ -69,7 +69,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from .engine import DuDeEngine, EngineState
@@ -229,7 +229,7 @@ class RoundAlgo:
         return shard_map(body, mesh=eng.mesh,
                          in_specs=tuple(kind[k] for k in in_kinds),
                          out_specs=out if len(out) > 1 else out[0],
-                         check_rep=False)
+                         check_vma=False)
 
 
 def _make_dude(engine: DuDeEngine, name: str) -> RoundAlgo:

@@ -154,19 +154,21 @@ def topk_mask(x: jnp.ndarray, k: int, tile: int = TILE) -> jnp.ndarray:
     """
     if not 1 <= k <= tile:
         raise ValueError(f"topk k={k} must be in [1, {tile}]")
-    a = jnp.abs(_tiles(x.astype(jnp.float32), tile))
+    xt = _tiles(x, tile)
+    a = jnp.abs(xt.astype(jnp.float32))
     lane = lax.broadcasted_iota(jnp.int32, a.shape, a.ndim - 1)
     cur = a
-    keep = jnp.zeros(a.shape, bool)
+    keep = None
     for _ in range(k):
         m = jnp.max(cur, axis=-1, keepdims=True)
         cand = jnp.where(cur == m, lane, tile)   # lowest lane among maxima
         sel = jnp.min(cand, axis=-1, keepdims=True)
         hit = lane == sel
-        keep = keep | hit
+        keep = hit if keep is None else keep | hit
         cur = jnp.where(hit, -jnp.inf, cur)
-    keep = keep.reshape(x.shape)
-    return jnp.where(keep, x, jnp.zeros_like(x))
+    # select in the tiled view and reshape the values, not the bool mask:
+    # Mosaic cannot reshape (or build a constant of) an i1 tile vector
+    return jnp.where(keep, xt, jnp.zeros_like(xt)).reshape(x.shape)
 
 
 def ef_encode(x: jnp.ndarray, err: jnp.ndarray,
@@ -245,8 +247,14 @@ def commit_digest(*arrays) -> str:
 
 
 def touched_tiles(q: jnp.ndarray, tile: int = TILE) -> jnp.ndarray:
-    """Per-tile any-nonzero bitmap: ``q [..., P] -> bool [..., P//tile]``."""
-    return jnp.any(_tiles(q, tile) != 0, axis=-1)
+    """Per-tile any-nonzero bitmap: ``q [..., P] -> int8 0/1 [..., P//tile]``
+    (the storage dtype of the engine's bitmaps).
+
+    Built from ``min(max |q|, 1)`` in f32, with no bool vector anywhere:
+    Mosaic cannot lower ``any(q != 0)`` over the tile axis nor relayout the
+    i1 result, and this runs inside the sparse round kernel."""
+    a = jnp.abs(_tiles(q, tile).astype(jnp.float32))
+    return jnp.minimum(jnp.max(a, axis=-1), 1.0).astype(jnp.int8)
 
 
 def sparse_encode(q: jnp.ndarray, scale: jnp.ndarray, cap: int, k: int,

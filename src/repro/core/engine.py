@@ -31,11 +31,11 @@ segment ranges of the spec's shard table (``FlatSpec.shard_ranges``) —
 ``g_bar`` as ``P(axis)``, the ``[n, P]`` slabs as ``P(None, axis)``, masks
 and scalars replicated.  The round is elementwise on P (the worker-axis sum
 is local to each P-shard), so a sharded round moves ZERO bytes across
-devices; the fused Pallas backend runs per shard with
-``tile = gcd(P/k, DEFAULT_TILE)``.  The spec must be built shard-aligned:
-``make_flat_spec(tree, mesh_axis_size=k)`` with ``k`` the product of the
-chosen mesh axes.  Sharded and unsharded engines agree bit-for-bit on
-``g_bar`` (``tests/test_engine_sharded.py``).
+devices; the fused Pallas backend runs per shard with the tile that
+``DuDeEngine.tile`` derives from the shard's VMEM footprint.  The spec
+must be built shard-aligned: ``make_flat_spec(tree, mesh_axis_size=k)``
+with ``k`` the product of the chosen mesh axes.  Sharded and unsharded
+engines agree bit-for-bit on ``g_bar`` (``tests/test_engine_sharded.py``).
 
 ``core/dude.py`` re-exports the historical pytree API (``dude_commit`` /
 ``dude_round`` / ``dude_round_indexed``) as thin ravel->engine->unravel
@@ -51,13 +51,12 @@ algorithms").
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import checkify
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from .compression import (
@@ -65,7 +64,7 @@ from .compression import (
 )
 from .flatten import FlatSpec, make_flat_spec
 from ..kernels.dude_update import (
-    DEFAULT_TILE, SLOT_STREAMS, dude_round_apply_pallas,
+    SLOT_STREAMS, derive_tile, dude_round_apply_pallas,
     dude_round_apply_q_pallas, dude_round_apply_sparse_pallas,
     dude_update_pallas,
 )
@@ -285,13 +284,19 @@ class DuDeEngine:
 
     @property
     def tile(self) -> int:
-        # Interpret mode evaluates one Python kernel body per grid step, so
-        # collapse to a single [n, P/k] program; on hardware use the largest
-        # tile <= DEFAULT_TILE that divides the local shard (P/k is a
-        # multiple of the pad lane count, so this is always >= PAD_MULTIPLE).
+        """Lanes per grid step of the fused kernels (one place decides).
+
+        Interpret mode evaluates one Python kernel body per grid step, so
+        it collapses to a single ``[n, P/k]`` program.  On hardware the tile
+        is the widest one whose double-buffered blocks and temporaries fit
+        the kernels' VMEM budget, from n, the slab dtype and the stream
+        count (sized for AdamW's two slot streams, so one tile serves every
+        optimizer); the last block may be ragged."""
         if self._interpret():
             return self.shard_P
-        return math.gcd(self.shard_P, DEFAULT_TILE)
+        slab = 1 if self.compressed else jnp.dtype(self.buffer_dtype).itemsize
+        return derive_tile(self.shard_P, self.n_workers, slab,
+                           max(SLOT_STREAMS.values()), self.compressed)
 
     def _interpret(self) -> bool:
         if self.interpret is not None:
@@ -340,7 +345,7 @@ class DuDeEngine:
 
     def _shmap(self, body, in_specs, out_specs):
         return shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+                         out_specs=out_specs, check_vma=False)
 
     # --------------------------------------------------------------- init
 
@@ -466,7 +471,7 @@ class DuDeEngine:
             if sparse:
                 # keep the invariant "bitmap == touched_tiles(q row)"
                 gw_t = jax.lax.dynamic_update_index_in_dim(
-                    targs[0], touched_tiles(q).astype(jnp.int8), w, axis=0)
+                    targs[0], touched_tiles(q), w, axis=0)
                 out += (gw_t,)
             return out
 
@@ -729,9 +734,11 @@ class DuDeEngine:
                         interpret=self._interpret())
                     scales = (gw_s, infl_s)
                 else:
+                    # the kernel widens fresh rows itself: an f32 copy of
+                    # a bf16 [n, P] gradient slab here would cost 4nP bytes
                     gw, infl, g_bar, w_new, new_leaves = \
                         dude_round_apply_pallas(
-                            b, a, f.astype(jnp.float32), st.g_workers,
+                            b, a, f, st.g_workers,
                             st.inflight, st.g_bar, w, tuple(leaves), bc,
                             kind=opt.name, hp=opt.hparams, tile=self.tile,
                             interpret=self._interpret())
@@ -942,11 +949,10 @@ class DuDeEngine:
     def _round_pallas(self, state, fresh, sm, cm, params, eta):
         """Fused single-pass kernel; optional in-pass SGD apply.  Under
         shard_map the kernel sees the local ``[n, P/k]`` slabs and tiles
-        them with ``gcd(P/k, DEFAULT_TILE)``."""
+        them with ``self.tile``."""
         w = params if params is not None else jnp.zeros_like(state.g_bar)
         gw, infl, g_bar, w_new = dude_update_pallas(
-            cm, sm, fresh.astype(jnp.float32), state.g_workers,
-            state.inflight, state.g_bar, w,
+            cm, sm, fresh, state.g_workers, state.inflight, state.g_bar, w,
             eta=float(eta) if eta is not None else 0.0,
             tile=self.tile, interpret=self._interpret(),
         )
@@ -1021,8 +1027,9 @@ class DuDeEngine:
         gate costs O(n * P/128) metadata reads, never payload."""
         act = cm[:, None] & ((st.gw_touched | st.in_touched) != 0)
         any_t = jnp.any(act, axis=0)                     # [t_local]
-        return jnp.any(any_t.reshape(-1, self.tile // self.codec.tile),
-                       axis=-1).astype(jnp.int32)
+        per = self.tile // self.codec.tile
+        any_t = jnp.pad(any_t, (0, -any_t.shape[0] % per))  # ragged block
+        return jnp.any(any_t.reshape(-1, per), axis=-1).astype(jnp.int32)
 
     def _round_sparse_reference(self, state, fresh, sm, cm):
         """Tile-gated masked sweep — the plain-jnp oracle of the sparse
@@ -1049,7 +1056,7 @@ class DuDeEngine:
         q_f, s_f = codec.encode(fresh.astype(jnp.float32))
         infl_q = jnp.where(sm[:, None], q_f, state.inflight)
         infl_s = jnp.where(sm[:, None], s_f, state.infl_scale)
-        in_t = jnp.where(sm[:, None], touched_tiles(q_f).astype(jnp.int8),
+        in_t = jnp.where(sm[:, None], touched_tiles(q_f),
                          state.in_touched)
         return g_bar, gw_q, infl_q, gw_s, infl_s, gw_t, in_t
 
@@ -1084,7 +1091,7 @@ class DuDeEngine:
         infl_q = state.inflight.at[start_idx].set(q_f, mode="drop")
         infl_s = state.infl_scale.at[start_idx].set(s_f, mode="drop")
         in_t = state.in_touched.at[start_idx].set(
-            touched_tiles(q_f).astype(jnp.int8), mode="drop")
+            touched_tiles(q_f), mode="drop")
         return g_bar, gw_q, infl_q, gw_s, infl_s, gw_t, in_t
 
     def _round_pallas_sparse(self, state, fresh, sm, cm, params, eta):

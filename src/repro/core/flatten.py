@@ -45,7 +45,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 Pytree = Any
@@ -222,7 +222,7 @@ class FlatSpec:
         fn = shard_map(
             body, mesh=mesh, in_specs=PartitionSpec(plan.axes),
             out_specs=tuple(PartitionSpec(*lf.entries) for lf in plan.leaves),
-            check_rep=False)
+            check_vma=False)
         return jax.tree.unflatten(self.treedef, list(fn(flat)))
 
     def ravel_stacked_sharded(self, tree: Pytree, mesh,
@@ -283,7 +283,7 @@ class FlatSpec:
             body, mesh=mesh,
             in_specs=tuple(PartitionSpec(None, *lf.entries)
                            for lf in plan.leaves),
-            out_specs=PartitionSpec(None, plan.axes), check_rep=False)
+            out_specs=PartitionSpec(None, plan.axes), check_vma=False)
         return fn(*leaves)
 
 

@@ -27,7 +27,9 @@ from repro.launch.costs import model_flops_6nd, param_counts, roofline  # noqa: 
 from repro.launch.hlo_analysis import (  # noqa: E402
     analyze_collectives, cost_analysis_dict, full_p_tensors, memory_stats,
 )
-from repro.launch.mesh import HW, make_production_mesh, mesh_num_devices  # noqa: E402
+from repro.launch.mesh import (  # noqa: E402
+    HW, make_mesh, make_production_mesh, mesh_num_devices,
+)
 from repro.launch.steps import (                          # noqa: E402
     INPUT_SHAPES,
     shape_supported,
@@ -38,10 +40,8 @@ def _host_mesh(spec: str):
     """``"DxM"`` -> a (data, model) mesh over the FIRST D*M host devices —
     the CI-scale twin of the production mesh (the 512-device override is
     already in force, so any small shape fits)."""
-    import numpy as np
     d, m = (int(x) for x in spec.split("x"))
-    devs = np.asarray(jax.devices()[: d * m]).reshape(d, m)
-    return jax.sharding.Mesh(devs, ("data", "model"))
+    return make_mesh((d, m), ("data", "model"), devices=jax.devices()[: d * m])
 
 
 def run_one(arch: str, shape_name: str, multi_pod: bool, *,

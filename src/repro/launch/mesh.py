@@ -7,6 +7,7 @@ Functions (not module constants) so importing never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 HW = {
     "peak_flops_bf16": 197e12,   # per chip
@@ -16,15 +17,22 @@ HW = {
 }
 
 
+def make_mesh(shape, axes, devices=None):
+    """The one way this repo builds a mesh: every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes since JAX 0.7, and the
+    partitioner-driven code here (``with_sharding_constraint`` in the shard
+    hook, GSPMD-placed gradients) needs ``Auto`` ones.  ``devices`` defaults
+    to all of ``jax.devices()``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(shape=(2, 4), axes=("data", "model")):
-    """Small mesh for CI-grade sharding tests (requires host-device override)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_num_devices(mesh) -> int:

@@ -42,6 +42,8 @@ from repro.core import (
     make_round_schedule,
     truncated_normal_speeds,
 )
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.sampling import make_worker_sample_fn
 from repro.runtime import (
     ARRIVAL_KINDS, SCENARIO_KINDS, ExponentialArrivals, FixedArrivals,
@@ -55,7 +57,7 @@ def parse_mesh(spec: str):
     if spec in ("none", ""):
         return None
     d, m = (int(x) for x in spec.split("x"))
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))
 
 
 def main():
@@ -167,6 +169,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     try:
         config = TrainerConfig(

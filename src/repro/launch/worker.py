@@ -37,6 +37,7 @@ import jax
 
 from repro.configs import get_config
 from repro.core.flatten import make_flat_spec
+from repro.launch.cache import use_compile_cache
 from repro.launch.sampling import make_worker_sample_fn
 from repro.launch.steps import abstract_params
 from repro.models import loss_fn
@@ -79,6 +80,7 @@ def main():
                     help="re-dial attempts after a dropped connection "
                          "(0 = die with the first drop)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -19,12 +19,13 @@ Differences from the simulator, by design:
   loss EMA stays on device between record points;
 * worker model snapshots are flat ``[P]`` vectors (n of them — the price of
   physical staleness), handed out by the loop's ``deliver`` hook.  The
-  arrival step therefore does NOT donate its state: the freshest snapshot
-  aliases ``state.params``.  Under a compressed ``commit_format`` the n
-  snapshots are delta-encoded (tiled int8, ``core/compression.py``) against
-  the run-start master instead of stored as full copies — ~3.9x less
-  snapshot memory; commits themselves are compressed inside
-  ``DuDeEngine.commit`` (int8 payload + per-tile scales + EF residual).
+  arrival step therefore donates the server slabs but NOT
+  ``state.params``: the freshest snapshot aliases it.  Under a compressed
+  ``commit_format`` the n snapshots are delta-encoded (tiled int8,
+  ``core/compression.py``) against the run-start master instead of stored
+  as full copies — ~3.9x less snapshot memory; commits themselves are
+  compressed inside ``DuDeEngine.commit`` (int8 payload + per-tile scales
+  + EF residual).
 
 The per-arrival math lives in ``_RunSession`` — one object exposing the
 ``on_arrival`` / ``deliver`` callbacks ``drive_arrivals`` wants, plus the
@@ -333,19 +334,21 @@ class _RunSession:
             self.payload_bytes += nbytes
             self.wire_bytes += self._commit_frame_nbytes(
                 w, job, self._row_manifest, nbytes)
-            self.state, g_dir = r._step_sparse(
+            self.state = r._step_sparse(
                 FlatTrainState(st.params, st.opt, srv), jnp.int32(w), wire)
         else:
-            self.state, g_dir = r._step(self.state, jnp.int32(w), gflat,
-                                        jnp.int32(view.tau))
+            st = self.state
+            self.state = r._step(st.params, st.opt, st.engine, jnp.int32(w),
+                                 gflat, jnp.int32(view.tau))
         # device-side EMA; the queue keeps the host <= depth steps ahead
-        # (g_dir comes out of the arrival step, so waiting on it bounds
-        # the whole grad+commit+apply chain of that arrival)
+        # (the step counter comes out of the arrival step, so waiting on it
+        # bounds the whole grad+commit+apply chain of that arrival without
+        # holding a [P] output alive)
         loss = jnp.asarray(loss, jnp.float32)
         rn = self.running
         self.running = (loss if rn is None
                         else self.ema * rn + (1 - self.ema) * loss)
-        self.queue.push((self.running, g_dir))
+        self.queue.push((self.running, self.state.opt.step))
         it_after = view.iters + 1
         if it_after % self.record_every == 0:
             self.times.append(view.t)
@@ -356,7 +359,6 @@ class _RunSession:
             else:
                 self.losses.append(float(self.running))
             # norm of the RAW arriving gradient — what SimResult records
-            # (the folded direction g_dir only gates the device queue)
             self.gnorms.append(float(jnp.sqrt(jnp.sum(jnp.square(gflat)))))
         return True  # every async rule applies every arrival
 
@@ -415,8 +417,10 @@ class AsyncRunner:
                 spec, engine.mesh, engine.paxes)
         self._ravel = jax.jit(lambda g: spec.ravel(g, jnp.float32),
                               **ravel_kw)
-        # NOT donated: the freshest worker snapshot aliases state.params
-        self._step = jax.jit(self._arrival_step)
+        # the server slabs are donated (updated in place, not copied per
+        # arrival); params are not: the freshest worker snapshot aliases
+        # them.  The queue waits on the new opt step, so opt is kept too.
+        self._step = jax.jit(self._arrival_step, donate_argnums=(2,))
         # Compressed commit formats also delta-encode the n worker model
         # snapshots against a fixed master base (run() start) instead of
         # keeping n full [P] f32 copies: snapshot w is stored as the tiled
@@ -444,7 +448,7 @@ class AsyncRunner:
                 t_new = state.opt.step + 1
                 pf, slots = self.fopt.update(state.params, g,
                                              state.opt.slots, t_new)
-                return FlatTrainState(pf, FlatOptState(t_new, slots), srv), g
+                return FlatTrainState(pf, FlatOptState(t_new, slots), srv)
 
             self._step_sparse = jax.jit(_fold_step)
         if self._compressed:
@@ -468,14 +472,15 @@ class AsyncRunner:
                     lambda base, q, s: spec.unravel(
                         base + codec.decode(q, s)))
 
-    def _arrival_step(self, state: FlatTrainState, worker, grad, tau):
+    def _arrival_step(self, params, opt: FlatOptState, srv, worker, grad,
+                      tau) -> FlatTrainState:
         """One server iteration: algo rule (commit for DuDe, s(τ)-damped
         commit for the staleness family) + flat apply, all elementwise on
         the (possibly P-sharded) slabs."""
-        srv, g = self.algo.arrival(state.engine, worker, grad, tau)
-        t_new = state.opt.step + 1
-        pf, slots = self.fopt.update(state.params, g, state.opt.slots, t_new)
-        return FlatTrainState(pf, FlatOptState(t_new, slots), srv), g
+        srv, g = self.algo.arrival(srv, worker, grad, tau)
+        t_new = opt.step + 1
+        pf, slots = self.fopt.update(params, g, opt.slots, t_new)
+        return FlatTrainState(pf, FlatOptState(t_new, slots), srv)
 
     # ------------------------------------------------------------- state
 
@@ -526,7 +531,8 @@ class AsyncRunner:
         dispatch-deterministic per worker (the multi-host convention — use
         it to replay a ``HostRunner`` trace); ``record_digests`` stamps
         every arrival's gradient (``AsyncResult.digests``) for comparison
-        against a recorded multi-host run.
+        against a recorded multi-host run.  ``state``'s server slabs are
+        donated to the first arrival step: use the result's state after.
         """
         n = self.engine.n_workers
         if process.n != n:

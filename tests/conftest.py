@@ -8,6 +8,8 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+from repro.launch.mesh import make_mesh  # noqa: E402
+
 jax.config.update("jax_enable_x64", False)
 
 
@@ -32,4 +34,4 @@ def collective_counts(hlo: str) -> dict:
 
 def p_mesh():
     """The NDEV-device 1-axis ("p") mesh every sharded suite runs on."""
-    return jax.make_mesh((NDEV,), ("p",))
+    return make_mesh((NDEV,), ("p",))

@@ -15,6 +15,7 @@ from repro.api import (
 from repro.core import ROUND_ALGOS, make_algo, make_round_algo
 from repro.core.engine import DuDeEngine
 from repro.core.flatten import make_flat_spec
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.optim import sgd
 
@@ -360,7 +361,7 @@ def test_trainer_abstract_input_specs_and_lower():
     """input_specs covers the full step signature and the session lowers
     with its shardings (the dryrun path, in miniature)."""
     cfg = _tiny_cfg()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for algo in ("dude", "fedbuff"):
         session = Trainer.abstract(TrainerConfig(arch=cfg, algo=algo,
                                                  mesh=mesh))

@@ -27,6 +27,7 @@ import pytest
 from conftest import NDEV, collective_counts, multidevice, p_mesh
 from repro.core.engine import BACKENDS, DuDeEngine
 from repro.core.flatten import make_flat_spec
+from repro.launch.mesh import make_mesh
 
 def _tree(rng):
     return {
@@ -138,7 +139,7 @@ def test_constrain_grads_emits_reduce_scatter():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     cfg = get_config("qwen2_0_5b").smoke()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     n = cfg.n_workers
     dude_cfg = DuDeConfig(n, jnp.float32)
     key = jax.random.PRNGKey(1)

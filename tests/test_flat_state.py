@@ -41,6 +41,7 @@ import pytest
 from conftest import NDEV, collective_counts, multidevice, p_mesh
 from repro.core.engine import BACKENDS, DuDeEngine
 from repro.core.flatten import make_flat_spec
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw, flat_twin, momentum_sgd, sgd
 
 OPTIMIZERS = {
@@ -193,7 +194,7 @@ def test_slot_shardings_match_param_shardings(opt_name):
     from repro.sharding import param_shardings, slot_shardings
 
     cfg = get_config("qwen2_0_5b").smoke()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = OPTIMIZERS[opt_name]()
     params = abstract_params(cfg)
     opt_state = jax.eval_shape(opt.init, params)
@@ -388,7 +389,7 @@ def test_flat_train_step_single_params_allgather():
     from repro.models import lm_init
 
     cfg = get_config("qwen2_0_5b").smoke()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     n = cfg.n_workers
     dude_cfg = DuDeConfig(n, jnp.float32)
     opt = momentum_sgd(0.05)

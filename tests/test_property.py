@@ -20,6 +20,7 @@ from repro.core.compression import (
     CommitCodec, dequantize, quantize, topk_mask,
 )
 from repro.data import dirichlet_partition, label_distribution
+from repro.launch.mesh import make_mesh
 
 SET = settings(max_examples=25, deadline=None)
 
@@ -253,7 +254,7 @@ def test_tp_exchange_roundtrip_random_layouts(n_leaves, stacked_n, seed):
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.flatten import make_flat_spec
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(seed)
     dtypes = [jnp.float32, jnp.bfloat16]
     tree, shardings = {}, {}

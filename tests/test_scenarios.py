@@ -224,7 +224,8 @@ def test_staleness_arrival_step_zero_collective_hlo_sharded():
     runner = AsyncRunner(eng, "dude_hinge", sgd(LR), _grad_fn)
     state = runner.init_state(tree)
     gflat = runner._ravel(jax.tree.map(jnp.ones_like, tree))
-    hlo = runner._step.lower(state, jnp.int32(1), gflat,
+    hlo = runner._step.lower(state.params, state.opt, state.engine,
+                             jnp.int32(1), gflat,
                              jnp.int32(6)).compile().as_text()
     counts = {k: v for k, v in collective_counts(hlo).items() if v}
     assert not counts, f"staleness arrival step has collectives: {counts}"

@@ -47,13 +47,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, json
 from repro.configs import get_config
 from repro.core.dude import DuDeConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_engine, make_train_step, train_batch_specs, abstract_train_state
 from repro.models import lm_init
 from repro.optim import sgd
 import numpy as np
 
 cfg = get_config("qwen2_0_5b").smoke()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 n = cfg.n_workers
 dude_cfg = DuDeConfig(n, jnp.float32)
 with mesh:

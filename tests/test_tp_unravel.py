@@ -34,13 +34,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from conftest import NDEV, collective_counts, multidevice
 from repro.core.flatten import make_flat_spec
+from repro.launch.mesh import make_mesh
 
 N_STACK = 3  # worker dim for the reverse-path tests
 
 
 def dm_mesh():
     """The (data=2, model=4) mesh the TP suite runs on (8 devices)."""
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def _tree(rng):
