@@ -54,6 +54,7 @@ from ..launch.steps import (
 )
 from ..models import lm_init
 from ..optim import FlatTrainState, flat_twin
+from ..runtime import spans
 from .config import ConfigError, TrainerConfig
 
 Pytree = Any
@@ -190,9 +191,10 @@ class Trainer:
         if self.state is None:
             raise ConfigError(
                 "abstract session has no state; use Trainer.create/restore")
-        self.state, metrics = self._jit()(
-            self.state, batch, jnp.asarray(start_mask),
-            jnp.asarray(commit_mask))
+        with spans.span(spans.STEP, round=self.rounds):
+            self.state, metrics = self._jit()(
+                self.state, batch, jnp.asarray(start_mask),
+                jnp.asarray(commit_mask))
         self.rounds += 1
         return metrics
 

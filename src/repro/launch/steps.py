@@ -55,6 +55,7 @@ from ..models import forward, init_decode_caches, lm_init, loss_fn, prefill
 from ..models.config import ModelConfig
 from ..models.stubs import token_shape
 from ..optim import FlatOptState, FlatTrainState, OptState, flat_twin, sgd
+from ..runtime import spans
 from ..sharding import (
     batch_sharding,
     cache_shardings,
@@ -239,45 +240,50 @@ def make_train_step(cfg: ModelConfig, mesh=None, opt=None,
         gradients that stay resident on their shard) and psum-scatter the
         raveled slab straight into the shard each device owns.
         """
-        split = (D > 1 and all(x.ndim >= 2 and x.shape[1] % D == 0
-                               for x in jax.tree.leaves(batch)))
-        if D > 1 and not split:
-            _warn_unsplittable(batch, D)
-        vbatch = batch
-        if split:
-            vbatch = jax.tree.map(
-                lambda x: jnp.swapaxes(
-                    x.reshape((x.shape[0], D, x.shape[1] // D)
-                              + x.shape[2:]), 0, 1
-                ).reshape((D * x.shape[0], x.shape[1] // D) + x.shape[2:]),
-                batch)
-        grads, losses = jax.vmap(per_worker_grad, in_axes=(None, 0))(params, vbatch)
-        if tp_plan is not None:
-            # reverse TP-native exchange: TP-layout gradient leaves ->
-            # [n, P] slab shards, no replicated [n, P] intermediate (the
-            # data-axis reduction lands on the TP blocks at the shard_map
-            # boundary, bounded by each leaf's segment)
-            fresh = engine.spec.ravel_stacked_sharded(
-                grads, mesh, dtype=gdt, plan=tp_plan)
+        with jax.named_scope(spans.BACKWARD):
+            split = (D > 1 and all(x.ndim >= 2 and x.shape[1] % D == 0
+                                   for x in jax.tree.leaves(batch)))
+            if D > 1 and not split:
+                _warn_unsplittable(batch, D)
+            vbatch = batch
+            if split:
+                vbatch = jax.tree.map(
+                    lambda x: jnp.swapaxes(
+                        x.reshape((x.shape[0], D, x.shape[1] // D)
+                                  + x.shape[2:]), 0, 1
+                    ).reshape((D * x.shape[0], x.shape[1] // D)
+                              + x.shape[2:]),
+                    batch)
+            grads, losses = jax.vmap(per_worker_grad,
+                                     in_axes=(None, 0))(params, vbatch)
+        with jax.named_scope(spans.RAVEL):
+            if tp_plan is not None:
+                # reverse TP-native exchange: TP-layout gradient leaves ->
+                # [n, P] slab shards, no replicated [n, P] intermediate (the
+                # data-axis reduction lands on the TP blocks at the shard_map
+                # boundary, bounded by each leaf's segment)
+                fresh = engine.spec.ravel_stacked_sharded(
+                    grads, mesh, dtype=gdt, plan=tp_plan)
+                return fresh, losses
+            if leaf_sh is not None:
+                grads = jax.tree.map(jax.lax.with_sharding_constraint,
+                                     grads, leaf_sh)
+            # The barrier keeps XLA from fusing the backward into the ravel's
+            # concatenate: with a large-vocab embedding gradient in that fusion
+            # the TPU compile of the step takes minutes instead of seconds.
+            grads = jax.lax.optimization_barrier(grads)
+            # ravel INSIDE the constraint: the stacked backward output lands
+            # directly in the engine's slab layout instead of whatever per-leaf
+            # layout GSPMD would pick for the pytree.
+            fresh = engine.spec.ravel_stacked(grads, gdt)
+            if split:
+                # [D*n, P] partial grads, rows resident per data-shard
+                fresh = jax.lax.with_sharding_constraint(
+                    fresh, NamedSharding(mesh, P("data", None)))
+                fresh = rs_fn(fresh)  # -> [n, P] in the engine slab sharding
+            elif flat_sh is not None:
+                fresh = jax.lax.with_sharding_constraint(fresh, flat_sh)
             return fresh, losses
-        if leaf_sh is not None:
-            grads = jax.tree.map(jax.lax.with_sharding_constraint, grads, leaf_sh)
-        # The barrier keeps XLA from fusing the backward into the ravel's
-        # concatenate: with a large-vocab embedding gradient in that fusion
-        # the TPU compile of the step takes minutes instead of seconds.
-        grads = jax.lax.optimization_barrier(grads)
-        # ravel INSIDE the constraint: the stacked backward output lands
-        # directly in the engine's slab layout instead of whatever per-leaf
-        # layout GSPMD would pick for the pytree.
-        fresh = engine.spec.ravel_stacked(grads, gdt)
-        if split:
-            # [D*n, P] partial grads, rows resident per data-shard
-            fresh = jax.lax.with_sharding_constraint(
-                fresh, NamedSharding(mesh, P("data", None)))
-            fresh = rs_fn(fresh)  # -> [n, P] in the engine slab sharding
-        elif flat_sh is not None:
-            fresh = jax.lax.with_sharding_constraint(fresh, flat_sh)
-        return fresh, losses
 
     fopt = flat_twin(opt)
     repl_sh = None
@@ -286,45 +292,48 @@ def make_train_step(cfg: ModelConfig, mesh=None, opt=None,
 
     def flat_train_step(state: FlatTrainState, batch,
                         start_mask, commit_mask):
-        if tp_plan is not None:
-            # TP-native path: each leaf's flat range is copied straight
-            # out of the P-shards into its Megatron-TP layout via the
-            # plan's ppermute ring — no device ever holds the full [P]
-            # vector; the forward consumes the TP blocks in place.
-            params = engine.spec.unravel_sharded(
-                state.params, mesh, plan=tp_plan)
-        else:
-            pf = state.params
-            if repl_sh is not None:
-                # THE one all-gather per step: materialize the full [P]
-                # vector once; every leaf slice below is then local, and
-                # the forward consumes the leaves without further param
-                # collectives (re-sharding them per-leaf here would turn
-                # into FSDP-style per-layer re-gathers).
-                pf = jax.lax.with_sharding_constraint(pf, repl_sh)
-            # slice+reshape+cast to the per-leaf target dtypes recorded in
-            # the FlatSpec (f32 masters feed a bf16 forward at large scale)
-            params = engine.spec.unravel(pf)
+        with jax.named_scope(spans.UNRAVEL):
+            if tp_plan is not None:
+                # TP-native path: each leaf's flat range is copied straight
+                # out of the P-shards into its Megatron-TP layout via the
+                # plan's ppermute ring — no device ever holds the full [P]
+                # vector; the forward consumes the TP blocks in place.
+                params = engine.spec.unravel_sharded(
+                    state.params, mesh, plan=tp_plan)
+            else:
+                pf = state.params
+                if repl_sh is not None:
+                    # THE one all-gather per step: materialize the full [P]
+                    # vector once; every leaf slice below is then local, and
+                    # the forward consumes the leaves without further param
+                    # collectives (re-sharding them per-leaf here would turn
+                    # into FSDP-style per-layer re-gathers).
+                    pf = jax.lax.with_sharding_constraint(pf, repl_sh)
+                # slice+reshape+cast to the per-leaf target dtypes recorded in
+                # the FlatSpec (f32 masters feed a bf16 forward at large scale)
+                params = engine.spec.unravel(pf)
         fresh, losses = fresh_grads(params, batch)
-        if algo.fused_apply:
-            srv_state, _, pf_new, opt_new = engine.round_apply(
-                state.engine, fresh, start_mask, commit_mask,
-                state.params, state.opt, fopt)
-            applied = jnp.array(True)
-        else:
-            srv_state, g, applied = algo.round(
-                state.engine, fresh, start_mask, commit_mask)
-            # gated flat apply: slots/params/step only advance on rounds
-            # the rule actually applies (FedBuff holds until its buffer
-            # fills); everything stays elementwise on the sharded [P] slabs.
-            t_new = state.opt.step + applied.astype(jnp.int32)
-            pf_up, slots_up = fopt.update(state.params, g,
-                                          state.opt.slots, t_new)
-            pf_new = jnp.where(applied, pf_up, state.params)
-            slots_new = jax.tree.map(
-                lambda u, o: jnp.where(applied, u, o),
-                slots_up, state.opt.slots)
-            opt_new = FlatOptState(t_new, slots_new)
+        with jax.named_scope(spans.ROUND):
+            if algo.fused_apply:
+                srv_state, _, pf_new, opt_new = engine.round_apply(
+                    state.engine, fresh, start_mask, commit_mask,
+                    state.params, state.opt, fopt)
+                applied = jnp.array(True)
+            else:
+                srv_state, g, applied = algo.round(
+                    state.engine, fresh, start_mask, commit_mask)
+                # gated flat apply: slots/params/step only advance on rounds
+                # the rule actually applies (FedBuff holds until its buffer
+                # fills); everything stays elementwise on the sharded [P]
+                # slabs.
+                t_new = state.opt.step + applied.astype(jnp.int32)
+                pf_up, slots_up = fopt.update(state.params, g,
+                                              state.opt.slots, t_new)
+                pf_new = jnp.where(applied, pf_up, state.params)
+                slots_new = jax.tree.map(
+                    lambda u, o: jnp.where(applied, u, o),
+                    slots_up, state.opt.slots)
+                opt_new = FlatOptState(t_new, slots_new)
         metrics = {"loss": jnp.mean(losses),
                    "applied": applied.astype(jnp.float32)}
         # indexed backend: cumulative commits/latches dropped by the static

@@ -491,8 +491,8 @@ def run_worker(transport_factory: Callable, workers: Sequence[int],
         codec = CommitCodec(format=fmt, tile=int(meta["tile"]),
                             topk=int(meta["topk"]))
         base = jnp.asarray(base_np)
-        # textually identical to the runner's _snap_unravel/_unravel/_ravel
-        # jits -> identical lowering -> bit-identical reconstruction
+        # the runner's _snap_unravel/_unravel/_ravel jits, less their
+        # profiler scopes (metadata only) -> bit-identical reconstruction
         if fmt == "topk_ef":
             unsnap = jax.jit(lambda row: spec.unravel(
                 base + sparse_decode(row, P)))

@@ -65,6 +65,7 @@ from ..core.algos import AsyncAlgo, make_async_algo
 from ..core.compression import commit_digest
 from ..core.engine import DuDeEngine
 from ..optim import FlatOptState, FlatTrainState, flat_twin
+from . import spans
 from .arrivals import ArrivalProcess, ArrivalTrace
 from .loop import LoopStats, drive_arrivals
 
@@ -105,13 +106,14 @@ class DeviceQueue:
             raise ValueError(f"queue depth {depth} must be >= 1")
         self.depth = depth
         self._q: collections.deque = collections.deque()
-        self.waits = 0  # times the host actually blocked (for tests/bench)
+        self.waits = 0  # times the host blocked: AsyncResult.queue_waits
 
     def push(self, value) -> None:
         self._q.append(value)
         if len(self._q) > self.depth:
             self.waits += 1
-            jax.block_until_ready(self._q.popleft())
+            with spans.span(spans.QUEUE_WAIT):
+                jax.block_until_ready(self._q.popleft())
 
     def flush(self) -> None:
         while self._q:
@@ -157,6 +159,9 @@ class AsyncResult:
     # snapshots, commits, heartbeats), summed over every link ever attached
     wire_sent: int = 0
     wire_recv: int = 0
+    # times the host blocked on the device queue (one ``dude.queue_wait``
+    # span each): the device was ``queue_depth`` steps behind the loop
+    queue_waits: int = 0
 
     @property
     def trace(self) -> ArrivalTrace:
@@ -207,6 +212,7 @@ class _RunSession:
         self.snap_encodes = 0
         self.snap_reuses = 0
         self.arrived = [0] * n   # per-worker collected jobs (job id source)
+        self.seq = -1            # the last arrival committed (span id)
         self.digests: Optional[list] = [] if record_digests else None
         self.times: list = []
         self.iters: list = []
@@ -263,7 +269,10 @@ class _RunSession:
         return r._unravel(self.worker_params[w])
 
     def deliver(self, worker: int) -> None:
-        if self.r._compressed:
+        with spans.span(spans.DELIVER, arrival=self.seq):
+            if not self.r._compressed:
+                self.worker_params[worker] = self.state.params
+                return
             params = self.state.params
             if self._snap_cache["params"] is not params:
                 self._snap_cache["params"] = params
@@ -273,8 +282,6 @@ class _RunSession:
             else:
                 self.snap_reuses += 1
             self.worker_snaps[worker] = self._snap_cache["enc"]
-        else:
-            self.worker_params[worker] = self.state.params
 
     def snapshot_arrays(self, worker: int) -> tuple:
         """The host-side arrays a delivery ships on the wire: the full f32
@@ -297,14 +304,17 @@ class _RunSession:
         worker's ``(loss, gflat)`` on the snapshot it holds, keyed per
         ``key_mode``."""
         w = view.worker
-        if self.key_mode == "worker":
-            k1 = worker_key(self.seed, w, self.arrived[w])
-            batch = self.sample_fn(w, self.rngs[w])
-        else:
-            self.key, k1 = jax.random.split(self.key)
-            batch = self.sample_fn(w, self.rng)
-        loss, g = self.r._grad(self.worker_model(w), batch, k1)
-        return loss, self.r._ravel(g)
+        worker_mode = self.key_mode == "worker"
+        with spans.span(spans.SAMPLE):
+            batch = self.sample_fn(w, self.rngs[w] if worker_mode
+                                   else self.rng)
+        with spans.span(spans.GRAD):
+            if worker_mode:
+                k1 = worker_key(self.seed, w, self.arrived[w])
+            else:
+                self.key, k1 = jax.random.split(self.key)
+            loss, g = self.r._grad(self.worker_model(w), batch, k1)
+            return loss, self.r._ravel(g)
 
     def commit(self, view, loss, gflat) -> bool:
         """One server iteration from an arrived gradient: encode/fold (or
@@ -317,54 +327,61 @@ class _RunSession:
         with ravel, so the simulator's pytree-side scaling stays bitwise
         identical."""
         r = self.r
-        w = int(view.worker)
-        job = self.arrived[w]
-        self.arrived[w] = job + 1
-        self.n_grads += 1
-        gflat = jnp.asarray(gflat)
-        if view.completeness != 1.0:
-            gflat = jnp.float32(view.completeness) * gflat
-        if self.digests is not None:
-            self.digests.append(commit_digest(np.asarray(gflat)))
-        if r._sparse:
-            st = self.state
-            srv, wire = r._encode(st.engine, jnp.int32(w), gflat)
-            self.wire_rows += 1
-            nbytes = r._wire_nbytes(wire)
-            self.payload_bytes += nbytes
-            self.wire_bytes += self._commit_frame_nbytes(
-                w, job, self._row_manifest, nbytes)
-            self.state = r._step_sparse(
-                FlatTrainState(st.params, st.opt, srv), jnp.int32(w), wire)
-        else:
-            st = self.state
-            self.state = r._step(st.params, st.opt, st.engine, jnp.int32(w),
-                                 gflat, jnp.int32(view.tau))
-        # device-side EMA; the queue keeps the host <= depth steps ahead
-        # (the step counter comes out of the arrival step, so waiting on it
-        # bounds the whole grad+commit+apply chain of that arrival without
-        # holding a [P] output alive)
-        loss = jnp.asarray(loss, jnp.float32)
-        rn = self.running
-        self.running = (loss if rn is None
-                        else self.ema * rn + (1 - self.ema) * loss)
+        self.seq = int(view.seq)
+        with spans.span(spans.COMMIT):
+            w = int(view.worker)
+            job = self.arrived[w]
+            self.arrived[w] = job + 1
+            self.n_grads += 1
+            gflat = jnp.asarray(gflat)
+            if view.completeness != 1.0:
+                gflat = jnp.float32(view.completeness) * gflat
+            if self.digests is not None:
+                self.digests.append(commit_digest(np.asarray(gflat)))
+            if r._sparse:
+                st = self.state
+                srv, wire = r._encode(st.engine, jnp.int32(w), gflat)
+                self.wire_rows += 1
+                nbytes = r._wire_nbytes(wire)
+                self.payload_bytes += nbytes
+                self.wire_bytes += self._commit_frame_nbytes(
+                    w, job, self._row_manifest, nbytes)
+                self.state = r._step_sparse(
+                    FlatTrainState(st.params, st.opt, srv), jnp.int32(w),
+                    wire)
+            else:
+                st = self.state
+                self.state = r._step(st.params, st.opt, st.engine,
+                                     jnp.int32(w), gflat, jnp.int32(view.tau))
+            # device-side EMA; the queue keeps the host <= depth steps
+            # ahead (the step counter comes out of the arrival step, so
+            # waiting on it bounds the whole grad+commit+apply chain of that
+            # arrival without holding a [P] output alive)
+            loss = jnp.asarray(loss, jnp.float32)
+            rn = self.running
+            self.running = (loss if rn is None
+                            else self.ema * rn + (1 - self.ema) * loss)
         self.queue.push((self.running, self.state.opt.step))
         it_after = view.iters + 1
         if it_after % self.record_every == 0:
-            self.times.append(view.t)
-            self.iters.append(it_after)
-            if self.eval_fn is not None:
-                self.losses.append(float(self.eval_fn(
-                    r.engine.spec.unravel(self.state.params))))
-            else:
-                self.losses.append(float(self.running))
-            # norm of the RAW arriving gradient — what SimResult records
-            self.gnorms.append(float(jnp.sqrt(jnp.sum(jnp.square(gflat)))))
+            with spans.span(spans.RECORD):
+                self.times.append(view.t)
+                self.iters.append(it_after)
+                if self.eval_fn is not None:
+                    self.losses.append(float(self.eval_fn(
+                        r.engine.spec.unravel(self.state.params))))
+                else:
+                    self.losses.append(float(self.running))
+                # norm of the RAW arriving gradient — what SimResult records
+                self.gnorms.append(float(jnp.sqrt(jnp.sum(
+                    jnp.square(gflat)))))
         return True  # every async rule applies every arrival
 
     def on_arrival(self, view) -> bool:
-        loss, gflat = self.grad_for(view)
-        return self.commit(view, loss, gflat)
+        with spans.span(spans.ARRIVAL, arrival=int(view.seq),
+                        worker=int(view.worker), tau=int(view.tau)):
+            loss, gflat = self.grad_for(view)
+            return self.commit(view, loss, gflat)
 
     # --------------------------------------------------------------- result
 
@@ -378,6 +395,7 @@ class _RunSession:
             wire_rows=self.wire_rows, wire_bytes=self.wire_bytes,
             payload_bytes=self.payload_bytes,
             snap_encodes=self.snap_encodes, snap_reuses=self.snap_reuses,
+            queue_waits=self.queue.waits,
             digests=None if self.digests is None else tuple(self.digests),
             **extra,
         )
@@ -406,8 +424,10 @@ class AsyncRunner:
         self.max_in_flight = max_in_flight
         self.queue_depth = queue_depth
         spec = engine.spec
-        self._grad = jax.jit(grad_fn)
-        self._unravel = jax.jit(spec.unravel)
+        # each jit is a named function under one device scope
+        # (``runtime/spans.py``), so a profile names its module and ops
+        self._grad = jax.jit(spans.scoped(spans.BACKWARD)(grad_fn))
+        self._unravel = jax.jit(spans.scoped(spans.UNRAVEL)(spec.unravel))
         ravel_kw = {}
         if engine.mesh is not None:
             # land the raveled gradient straight in the engine's segment-
@@ -415,8 +435,12 @@ class AsyncRunner:
             from ..sharding import flat_vec_sharding
             ravel_kw["out_shardings"] = flat_vec_sharding(
                 spec, engine.mesh, engine.paxes)
-        self._ravel = jax.jit(lambda g: spec.ravel(g, jnp.float32),
-                              **ravel_kw)
+
+        @spans.scoped(spans.RAVEL)
+        def ravel_grad(g):
+            return spec.ravel(g, jnp.float32)
+
+        self._ravel = jax.jit(ravel_grad, **ravel_kw)
         # the server slabs are donated (updated in place, not copied per
         # arrival); params are not: the freshest worker snapshot aliases
         # them.  The queue waits on the new opt step, so opt is kept too.
@@ -444,10 +468,12 @@ class AsyncRunner:
             self._encode = jax.jit(engine.encode_sparse_commit)
 
             def _fold_step(state, worker, row):
-                srv, g = engine.sparse_fold(state.engine, worker, row)
-                t_new = state.opt.step + 1
-                pf, slots = self.fopt.update(state.params, g,
-                                             state.opt.slots, t_new)
+                with jax.named_scope(spans.COMMIT):
+                    srv, g = engine.sparse_fold(state.engine, worker, row)
+                with jax.named_scope(spans.APPLY):
+                    t_new = state.opt.step + 1
+                    pf, slots = self.fopt.update(state.params, g,
+                                                 state.opt.slots, t_new)
                 return FlatTrainState(pf, FlatOptState(t_new, slots), srv)
 
             self._step_sparse = jax.jit(_fold_step)
@@ -458,28 +484,34 @@ class AsyncRunner:
                 # to the dense (q, scale) snapshot pair
                 from ..core.compression import sparse_decode
                 P = engine.P
-                self._snap_encode = jax.jit(
-                    lambda params, base: codec.encode_sparse(
-                        params.astype(jnp.float32) - base))
-                self._snap_unravel = jax.jit(
-                    lambda base, row: spec.unravel(
-                        base + sparse_decode(row, P)))
+
+                def snap_encode(params, base):
+                    return codec.encode_sparse(
+                        params.astype(jnp.float32) - base)
+
+                @spans.scoped(spans.UNRAVEL)
+                def snap_unravel(base, row):
+                    return spec.unravel(base + sparse_decode(row, P))
             else:
-                self._snap_encode = jax.jit(
-                    lambda params, base: codec.encode(
-                        params.astype(jnp.float32) - base))
-                self._snap_unravel = jax.jit(
-                    lambda base, q, s: spec.unravel(
-                        base + codec.decode(q, s)))
+                def snap_encode(params, base):
+                    return codec.encode(params.astype(jnp.float32) - base)
+
+                @spans.scoped(spans.UNRAVEL)
+                def snap_unravel(base, q, s):
+                    return spec.unravel(base + codec.decode(q, s))
+            self._snap_encode = jax.jit(snap_encode)
+            self._snap_unravel = jax.jit(snap_unravel)
 
     def _arrival_step(self, params, opt: FlatOptState, srv, worker, grad,
                       tau) -> FlatTrainState:
         """One server iteration: algo rule (commit for DuDe, s(τ)-damped
         commit for the staleness family) + flat apply, all elementwise on
         the (possibly P-sharded) slabs."""
-        srv, g = self.algo.arrival(srv, worker, grad, tau)
-        t_new = opt.step + 1
-        pf, slots = self.fopt.update(params, g, opt.slots, t_new)
+        with jax.named_scope(spans.COMMIT):
+            srv, g = self.algo.arrival(srv, worker, grad, tau)
+        with jax.named_scope(spans.APPLY):
+            t_new = opt.step + 1
+            pf, slots = self.fopt.update(params, g, opt.slots, t_new)
         return FlatTrainState(pf, FlatOptState(t_new, slots), srv)
 
     # ------------------------------------------------------------- state
