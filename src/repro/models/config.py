@@ -58,8 +58,11 @@ class ModelConfig:
     # runtime knobs
     scan_layers: bool = True
     remat: bool = True
-    # §Perf: compute the LM head + cross-entropy in sequence chunks inside a
-    # checkpointed scan — never materializes [T, V] logits (0 = off).
+    # §Perf: compute the LM head + cross-entropy in ceil(S / ce_chunk)
+    # vocabulary slices inside a checkpointed scan, each slice's logits as
+    # large as a ce_chunk-token chunk of [T, V], which is never materialized;
+    # the head's slices are the scan's xs, so its gradient is written once
+    # per slice (0 = off; S <= ce_chunk is the unchunked loss).
     ce_chunk: int = 0
 
     # DuDe / distribution defaults for this arch (overridable at launch)

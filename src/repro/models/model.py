@@ -71,6 +71,18 @@ def _inputs_to_h(params, batch, cfg: ModelConfig) -> jnp.ndarray:
     return h
 
 
+def _final_hidden(params, batch, cfg: ModelConfig, shard: ShardHook,
+                  use_window: bool = False):
+    """The normed last hidden state ``[B, S, d]`` and the aux loss."""
+    h = _inputs_to_h(params, batch, cfg)
+    B, S = h.shape[:2]
+    positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    h = shard(h, "act_resid")
+    h, _, aux = stack_apply(params["stack"], h, positions, cfg,
+                            shard=shard, use_window=use_window)
+    return rmsnorm(params["ln_f"], h, cfg.norm_eps), aux
+
+
 def forward(
     params: Pytree,
     batch: dict,
@@ -80,13 +92,7 @@ def forward(
     use_window: bool = False,
 ):
     """Full-sequence forward.  Returns (logits_f32, aux_loss)."""
-    h = _inputs_to_h(params, batch, cfg)
-    B, S = h.shape[:2]
-    positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    h = shard(h, "act_resid")
-    h, _, aux = stack_apply(params["stack"], h, positions, cfg,
-                            shard=shard, use_window=use_window)
-    h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
+    h, aux = _final_hidden(params, batch, cfg, shard, use_window)
     logits = _head(params, h, cfg).astype(jnp.float32)
     return shard(logits, "logits"), aux
 
@@ -99,6 +105,46 @@ def _masked_ce(logits: jnp.ndarray, labels: jnp.ndarray):
     return -jnp.sum(ll * mask), jnp.sum(mask)
 
 
+def _sliced_ce(params, h: jnp.ndarray, labels: jnp.ndarray, nv: int,
+               cfg: ModelConfig, shard: ShardHook):
+    """Returns (sum of -log p over unmasked labels, count), with the head and
+    its logits taken in ``nv`` vocabulary slices (see ``loss_fn``)."""
+    # the head's rows [V, d]: the tied embedding, or the kernel's columns
+    if cfg.tie_embeddings:
+        w = params["embed"]["embedding"]
+    else:
+        w = params["head"]["kernel"].T
+    V = w.shape[0]
+    vc = -(-V // nv)
+    pad = nv * vc - V
+    if pad:
+        w = jnp.pad(w, ((0, pad), (0, 0)))
+    w = w.reshape(nv, vc, -1)
+    offsets = jnp.arange(nv) * vc
+
+    # closed over, not a carry (a carry is saved once per slice for the
+    # backward); in f32, so the slices' shares of its cotangent add in f32
+    h32 = h.astype(jnp.float32)
+
+    def slice_stats(_, inp):
+        ws, off = inp
+        logits = h32.astype(cfg.dtype) @ ws.T.astype(cfg.dtype)
+        logits = logits.astype(jnp.float32)
+        logits = shard(logits, "logits")
+        col = jnp.arange(vc)
+        if pad:
+            logits = jnp.where(off + col < V, logits, -jnp.inf)
+        hit = col == (labels - off)[..., None]
+        picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        return None, (jax.nn.logsumexp(logits, axis=-1), picked)
+
+    _, (lse, picked) = jax.lax.scan(
+        jax.checkpoint(slice_stats), None, (w, offsets))
+    mask = (labels >= 0).astype(jnp.float32)
+    nll = jax.nn.logsumexp(lse, axis=0) - jnp.sum(picked, axis=0)
+    return jnp.sum(nll * mask), jnp.sum(mask)
+
+
 def loss_fn(
     params: Pytree,
     batch: dict,
@@ -108,44 +154,28 @@ def loss_fn(
 ) -> tuple[jnp.ndarray, dict]:
     """Next-token cross-entropy with -1-masked labels (+ MoE aux).
 
-    With ``cfg.ce_chunk > 0`` (and a single codebook) the LM head + CE run in
-    sequence chunks inside a checkpointed scan: the [T, V] logits tensor is
-    never materialized (fwd OR bwd) — the §Perf memory-term optimization for
-    large-vocab training (see EXPERIMENTS §Perf T2).
+    With ``cfg.ce_chunk > 0`` (and a single codebook) the LM head + CE run
+    over ``nv = ceil(S / ce_chunk)`` vocabulary slices of ``ceil(V / nv)``
+    rows inside a checkpointed scan: one slice's logits ``[B, S, V / nv]``
+    hold as many elements as a ``[B, ce_chunk, V]`` chunk, and the [T, V]
+    logits tensor is never materialized (fwd OR bwd).  Each slice's
+    logsumexp and label logit come out of the scan, and the loss combines
+    them.  The head's slices go in as the scan's ``xs``, not through its
+    closure: the cotangent of ``xs`` is the stacked ``ys``, so each slice of
+    the head's gradient is one matmul over all tokens, written once, where a
+    closed-over head would be re-accumulated in a full ``[V, d]`` f32 carry
+    once per iteration.  A V that the slices do not divide is padded, and
+    its padding masked out of the logits; ``nv == 1`` is the unchunked loss.
     """
     labels = batch["labels"]
-    if cfg.ce_chunk and cfg.num_codebooks == 1:
-        h = _inputs_to_h(params, batch, cfg)
-        B, S = h.shape[:2]
-        positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-        h = shard(h, "act_resid")
-        h, _, aux = stack_apply(params["stack"], h, positions, cfg, shard=shard)
-        h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
-        C = cfg.ce_chunk
-        nc = -(-S // C)
-        pad = nc * C - S
-        if pad:
-            h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
-            labels = jnp.pad(labels, ((0, 0), (0, pad)), constant_values=-1)
-        hc = h.reshape(B, nc, C, -1).transpose(1, 0, 2, 3)
-        lc = labels.reshape(B, nc, C).transpose(1, 0, 2)
-
-        def chunk_loss(carry, inp):
-            hs, ls = inp
-            logits = _head(params, hs, cfg).astype(jnp.float32)
-            logits = shard(logits, "logits")
-            s, c = _masked_ce(logits, ls)
-            tot, cnt = carry
-            return (tot + s, cnt + c), None
-
-        (tot, cnt), _ = jax.lax.scan(
-            jax.checkpoint(chunk_loss), (jnp.zeros(()), jnp.zeros(())), (hc, lc)
-        )
-        loss = tot / jnp.maximum(cnt, 1.0)
+    nv = -(-labels.shape[1] // cfg.ce_chunk) if cfg.ce_chunk else 1
+    if nv > 1 and cfg.num_codebooks == 1:
+        h, aux = _final_hidden(params, batch, cfg, shard)
+        s, c = _sliced_ce(params, h, labels, nv, cfg, shard)
     else:
         logits, aux = forward(params, batch, cfg, shard=shard)
         s, c = _masked_ce(logits, labels)
-        loss = s / jnp.maximum(c, 1.0)
+    loss = s / jnp.maximum(c, 1.0)
     total = loss + aux
     return total, {"loss": loss, "aux": aux}
 
