@@ -49,7 +49,9 @@ def _mlp_flops(T, d, f, gated: bool = True) -> float:
 def _moe_flops(cfg, T) -> float:
     d, E, k, f = cfg.d_model, cfg.num_experts, cfg.experts_per_tok, cfg.moe_d_ff
     router = 2 * T * d * E
-    routed_tokens = cfg.capacity_factor * T * k
+    # drop-free: every (token, slot) pair, of which a layer holding
+    # experts_held of E computes its share under even routing
+    routed_tokens = T * k * (cfg.experts_held or E) / E
     expert = 6 * routed_tokens * d * f
     shared = 6 * T * d * f * cfg.num_shared_experts
     return router + expert + shared

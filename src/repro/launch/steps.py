@@ -88,6 +88,8 @@ def shape_supported(cfg: ModelConfig, shape_name: str) -> tuple[bool, str]:
 # ------------------------------------------------------------- step builders
 
 PARAMS_LAYOUTS = ("replicated", "tp")
+# what each worker's loss_fn metrics give the round step's metrics
+STEP_METRICS = ("loss", "moe_rows_held", "moe_rows_dropped")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,7 +229,7 @@ def make_train_step(cfg: ModelConfig, mesh=None, opt=None,
         (total, metrics), grads = jax.value_and_grad(
             lambda p: loss_fn(p, wbatch, cfg, shard=shard), has_aux=True
         )(params)
-        return grads, metrics["loss"]
+        return grads, {k: metrics[k] for k in STEP_METRICS if k in metrics}
 
     def fresh_grads(params, batch):
         """Stacked backward -> [n, P] slab in the engine's grad layout.
@@ -254,8 +256,8 @@ def make_train_step(cfg: ModelConfig, mesh=None, opt=None,
                     ).reshape((D * x.shape[0], x.shape[1] // D)
                               + x.shape[2:]),
                     batch)
-            grads, losses = jax.vmap(per_worker_grad,
-                                     in_axes=(None, 0))(params, vbatch)
+            grads, wm = jax.vmap(per_worker_grad,
+                                 in_axes=(None, 0))(params, vbatch)
         with jax.named_scope(spans.RAVEL):
             if tp_plan is not None:
                 # reverse TP-native exchange: TP-layout gradient leaves ->
@@ -264,7 +266,7 @@ def make_train_step(cfg: ModelConfig, mesh=None, opt=None,
                 # boundary, bounded by each leaf's segment)
                 fresh = engine.spec.ravel_stacked_sharded(
                     grads, mesh, dtype=gdt, plan=tp_plan)
-                return fresh, losses
+                return fresh, wm
             if leaf_sh is not None:
                 grads = jax.tree.map(jax.lax.with_sharding_constraint,
                                      grads, leaf_sh)
@@ -283,7 +285,7 @@ def make_train_step(cfg: ModelConfig, mesh=None, opt=None,
                 fresh = rs_fn(fresh)  # -> [n, P] in the engine slab sharding
             elif flat_sh is not None:
                 fresh = jax.lax.with_sharding_constraint(fresh, flat_sh)
-            return fresh, losses
+            return fresh, wm
 
     fopt = flat_twin(opt)
     repl_sh = None
@@ -312,7 +314,7 @@ def make_train_step(cfg: ModelConfig, mesh=None, opt=None,
                 # slice+reshape+cast to the per-leaf target dtypes recorded in
                 # the FlatSpec (f32 masters feed a bf16 forward at large scale)
                 params = engine.spec.unravel(pf)
-        fresh, losses = fresh_grads(params, batch)
+        fresh, wm = fresh_grads(params, batch)
         with jax.named_scope(spans.ROUND):
             if algo.fused_apply:
                 srv_state, _, pf_new, opt_new = engine.round_apply(
@@ -334,8 +336,11 @@ def make_train_step(cfg: ModelConfig, mesh=None, opt=None,
                     lambda u, o: jnp.where(applied, u, o),
                     slots_up, state.opt.slots)
                 opt_new = FlatOptState(t_new, slots_new)
-        metrics = {"loss": jnp.mean(losses),
+        metrics = {"loss": jnp.mean(wm.pop("loss")),
                    "applied": applied.astype(jnp.float32)}
+        # MoE: (token, slot) pairs routed to held experts, and dropped (0),
+        # over the workers and layers
+        metrics.update({k: jnp.sum(v) for k, v in wm.items()})
         # indexed backend: cumulative commits/latches dropped by the static
         # index_width bound — the in-graph jax.debug warning's structured
         # twin, so drops show up in every step's metrics, not just stderr

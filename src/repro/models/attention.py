@@ -1,5 +1,6 @@
-"""Grouped-query attention with RoPE, qk-norm, QKV bias, sliding window,
-KV caches, and memory-bounded chunked softmax.
+"""Grouped-query attention with RoPE, qk-norm (per head, or over the whole
+projection), QKV bias, sliding window, KV caches, and memory-bounded chunked
+softmax.
 
 Three execution paths:
   * ``attention_ref``      — naive O(S^2) materialized scores (tests/oracles).
@@ -35,7 +36,7 @@ class AttnConfig:
     num_kv_heads: int
     head_dim: int
     qkv_bias: bool = False
-    qk_norm: bool = False
+    qk_norm: bool | str = False  # True: per head; "full": the whole q / k projection
     rope_theta: float = 1e4
     sliding_window: Optional[int] = None
     chunk: int = 512
@@ -50,7 +51,10 @@ def attention_init(key, cfg: AttnConfig) -> Pytree:
         "wv": dense_init(kv, d, K * hd, bias=cfg.qkv_bias),
         "wo": dense_init(ko, H * hd, d),
     }
-    if cfg.qk_norm:
+    if cfg.qk_norm == "full":
+        p["q_norm"] = rmsnorm_init(H * hd)
+        p["k_norm"] = rmsnorm_init(K * hd)
+    elif cfg.qk_norm:
         p["q_norm"] = rmsnorm_init(hd)
         p["k_norm"] = rmsnorm_init(hd)
     return p
@@ -59,10 +63,17 @@ def attention_init(key, cfg: AttnConfig) -> Pytree:
 def _project_qkv(p, x, positions, cfg: AttnConfig, shard: ShardHook):
     B, S, _ = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = dense(p["wq"], x).reshape(B, S, H, hd)
-    k = dense(p["wk"], x).reshape(B, S, K, hd)
+    full = cfg.qk_norm == "full"
+    q = dense(p["wq"], x)
+    if full:
+        q = rmsnorm(p["q_norm"], q)
+    q = q.reshape(B, S, H, hd)
+    k = dense(p["wk"], x)
+    if full:
+        k = rmsnorm(p["k_norm"], k)
+    k = k.reshape(B, S, K, hd)
     v = dense(p["wv"], x).reshape(B, S, K, hd)
-    if cfg.qk_norm:
+    if cfg.qk_norm and not full:
         q = rmsnorm(p["q_norm"], q)
         k = rmsnorm(p["k_norm"], k)
     q = apply_rope(q, positions, cfg.rope_theta)

@@ -22,7 +22,8 @@ class ModelConfig:
 
     # attention options
     qkv_bias: bool = False
-    qk_norm: bool = False
+    qk_norm: bool | str = False         # True: per head (Qwen3); "full": over
+                                        # the whole q / k projection (OLMoE)
     rope_theta: float = 1e4
     sliding_window: Optional[int] = None  # enables long_500k for dense archs
     attn_chunk: int = 512
@@ -34,11 +35,14 @@ class ModelConfig:
     prefix_layers: Tuple[str, ...] = ()
 
     # moe
-    num_experts: int = 0
+    num_experts: int = 0                # the router's width
     experts_per_tok: int = 0
     moe_d_ff: int = 0
     num_shared_experts: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0               # experts each MoE layer holds, from
+    expert_offset: int = 0              # expert_offset on (0: all of them)
+    norm_topk_prob: bool = True         # renormalise the top-k gates
+    router_aux_loss_coef: float = 0.01  # x the load-balancing loss
     dense_d_ff: int = 0                 # d_ff for 'attn' layers in MoE models
     mlp_gated: bool = True              # SwiGLU (False: GELU 2-matrix MLP)
 
@@ -110,6 +114,8 @@ class ModelConfig:
             vocab_size=512,
             num_experts=min(self.num_experts, 4) if self.num_experts else 0,
             experts_per_tok=min(self.experts_per_tok, 2) if self.experts_per_tok else 0,
+            experts_held=0,
+            expert_offset=0,
             moe_d_ff=128 if self.moe_d_ff else 0,
             ssm_state=16,
             sliding_window=64 if self.sliding_window else None,

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from .config import ModelConfig
 from .layers import dense, dense_init, embed, embedding_init, rmsnorm, rmsnorm_init
+from .moe import load_balancing_loss
 from .transformer import stack_apply, stack_caches, stack_init
 
 Pytree = Any
@@ -73,7 +74,8 @@ def _inputs_to_h(params, batch, cfg: ModelConfig) -> jnp.ndarray:
 
 def _final_hidden(params, batch, cfg: ModelConfig, shard: ShardHook,
                   use_window: bool = False):
-    """The normed last hidden state ``[B, S, d]`` and the aux loss."""
+    """The normed last hidden state ``[B, S, d]`` and the stack's aux (see
+    ``stack_apply``)."""
     h = _inputs_to_h(params, batch, cfg)
     B, S = h.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
@@ -94,7 +96,19 @@ def forward(
     """Full-sequence forward.  Returns (logits_f32, aux_loss)."""
     h, aux = _final_hidden(params, batch, cfg, shard, use_window)
     logits = _head(params, h, cfg).astype(jnp.float32)
-    return shard(logits, "logits"), aux
+    return shard(logits, "logits"), _aux_terms(aux, cfg)[0]
+
+
+def _aux_terms(aux, cfg: ModelConfig):
+    """The stack's aux -> (aux loss, step counters).  MoE: the router's
+    load-balancing loss times ``router_aux_loss_coef``, and the (token,
+    slot) pairs routed to held experts and dropped; else the zero scalar and
+    no counters."""
+    if not isinstance(aux, dict):
+        return aux, {}
+    loss = cfg.router_aux_loss_coef * load_balancing_loss(aux, cfg.num_experts)
+    return loss, {"moe_rows_held": aux["rows_held"],
+                  "moe_rows_dropped": aux["rows_dropped"]}
 
 
 def _masked_ce(logits: jnp.ndarray, labels: jnp.ndarray):
@@ -152,7 +166,8 @@ def loss_fn(
     *,
     shard: ShardHook = _id_hook,
 ) -> tuple[jnp.ndarray, dict]:
-    """Next-token cross-entropy with -1-masked labels (+ MoE aux).
+    """Next-token cross-entropy with -1-masked labels (+ MoE aux loss, and
+    the MoE step counters among the metrics).
 
     With ``cfg.ce_chunk > 0`` (and a single codebook) the LM head + CE run
     over ``nv = ceil(S / ce_chunk)`` vocabulary slices of ``ceil(V / nv)``
@@ -169,15 +184,16 @@ def loss_fn(
     """
     labels = batch["labels"]
     nv = -(-labels.shape[1] // cfg.ce_chunk) if cfg.ce_chunk else 1
+    h, aux = _final_hidden(params, batch, cfg, shard)
     if nv > 1 and cfg.num_codebooks == 1:
-        h, aux = _final_hidden(params, batch, cfg, shard)
         s, c = _sliced_ce(params, h, labels, nv, cfg, shard)
     else:
-        logits, aux = forward(params, batch, cfg, shard=shard)
+        logits = shard(_head(params, h, cfg).astype(jnp.float32), "logits")
         s, c = _masked_ce(logits, labels)
     loss = s / jnp.maximum(c, 1.0)
+    aux, counters = _aux_terms(aux, cfg)
     total = loss + aux
-    return total, {"loss": loss, "aux": aux}
+    return total, {"loss": loss, "aux": aux, **counters}
 
 
 # --------------------------------------------------------------------- decode

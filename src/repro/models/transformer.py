@@ -24,7 +24,7 @@ from .attention import (
 )
 from .config import ModelConfig
 from .layers import mlp, mlp_init, rmsnorm, rmsnorm_init
-from .moe import MoEConfig, moe_apply, moe_init
+from .moe import MoEConfig, moe_apply, moe_init, moe_stats_zero
 from .ssm import (
     Mamba2Config, MLSTMConfig, SLSTMConfig,
     mamba2_apply, mamba2_init, mamba2_init_state,
@@ -51,7 +51,8 @@ def moe_cfg(cfg: ModelConfig) -> MoEConfig:
     return MoEConfig(
         d_model=cfg.d_model, num_experts=cfg.num_experts,
         experts_per_tok=cfg.experts_per_tok, d_ff=cfg.moe_d_ff,
-        capacity_factor=cfg.capacity_factor,
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
+        norm_topk_prob=cfg.norm_topk_prob,
         num_shared_experts=cfg.num_shared_experts,
     )
 
@@ -133,7 +134,8 @@ def block_apply(
     shard: ShardHook = _id_hook,
     use_window: bool = False,
 ):
-    """Residual block.  Returns (x, new_cache)."""
+    """Residual block.  Returns (x, new_cache, aux): aux is an MoE layer's
+    router statistics (``moe.moe_stats_zero``), a zero scalar otherwise."""
     acfg = attn_cfg(cfg)
     if kind in ("attn", "moe"):
         h, new_cache = attention_apply(
@@ -145,9 +147,10 @@ def block_apply(
         if kind == "attn":
             x = x + mlp(params["mlp"], rmsnorm(params["ln2"], x, cfg.norm_eps))
             return x, new_cache, jnp.zeros((), jnp.float32)
-        y, aux = moe_apply(params["moe"], rmsnorm(params["ln2"], x, cfg.norm_eps),
-                           moe_cfg(cfg))
-        return x + y, new_cache, aux
+        y, stats = moe_apply(params["moe"],
+                             rmsnorm(params["ln2"], x, cfg.norm_eps),
+                             moe_cfg(cfg))
+        return x + y, new_cache, stats
 
     zero_aux = jnp.zeros((), jnp.float32)
     if kind == "mamba":
@@ -181,6 +184,21 @@ def block_apply(
 
 
 # ------------------------------------------------------------------ the stack
+
+def _aux_zero(cfg: ModelConfig):
+    if "moe" in cfg.block_pattern or "moe" in cfg.prefix_layers:
+        return moe_stats_zero(cfg.num_experts)
+    return jnp.zeros((), jnp.float32)
+
+
+def _add_aux(total, aux):
+    """Layers' aux summed: router statistics where the stack has MoE layers
+    (its other layers add nothing), zero scalars otherwise."""
+    if isinstance(total, dict):
+        return jax.tree.map(jnp.add, total, aux) if isinstance(aux, dict) \
+            else total
+    return total + aux
+
 
 def stack_init(key, cfg: ModelConfig) -> Pytree:
     """Stacked parameters: for each period position, leaves have a leading
@@ -231,8 +249,10 @@ def stack_apply(
     shard: ShardHook = _id_hook,
     use_window: bool = False,
 ):
-    """Run the full layer stack.  Returns (x, new_caches, aux_loss)."""
-    aux_total = jnp.zeros((), jnp.float32)
+    """Run the full layer stack.  Returns (x, new_caches, aux): the router
+    statistics summed over the MoE layers where the stack has any, else a
+    zero scalar."""
+    aux_total = _aux_zero(cfg)
     new_prefix = []
     for i, kind in enumerate(cfg.prefix_layers):
         c = caches["prefix"][i] if caches is not None else None
@@ -242,12 +262,12 @@ def stack_apply(
             shard=shard, use_window=use_window,
         )
         new_prefix.append(nc)
-        aux_total = aux_total + aux
+        aux_total = _add_aux(aux_total, aux)
 
     shared = params["shared_attn"]
 
     def group_fn(x, group_params, group_caches):
-        aux_g = jnp.zeros((), jnp.float32)
+        aux_g = _aux_zero(cfg)
         new_caches = []
         for pi, kind in enumerate(cfg.block_pattern):
             c = group_caches[pi] if group_caches is not None else None
@@ -257,7 +277,7 @@ def stack_apply(
                 shard=shard, use_window=use_window,
             )
             new_caches.append(nc)
-            aux_g = aux_g + aux
+            aux_g = _add_aux(aux_g, aux)
         return x, new_caches, aux_g
 
     if cfg.remat:
@@ -268,7 +288,7 @@ def stack_apply(
             def scan_body_nc(carry, gp):
                 xc, aux = carry
                 xc, _, aux_g = group_fn(xc, gp, None)
-                return (xc, aux + aux_g), None
+                return (xc, _add_aux(aux, aux_g)), None
 
             (x, aux_total), _ = jax.lax.scan(
                 scan_body_nc, (x, aux_total), params["groups"]
@@ -279,7 +299,7 @@ def stack_apply(
                 xc, aux = carry
                 gp, gc = scanned
                 xc, nc, aux_g = group_fn(xc, gp, gc)
-                return (xc, aux + aux_g), nc
+                return (xc, _add_aux(aux, aux_g)), nc
 
             (x, aux_total), new_groups = jax.lax.scan(
                 scan_body, (x, aux_total), (params["groups"], caches["groups"])
@@ -293,7 +313,7 @@ def stack_apply(
                 if caches is not None else None
             )
             x, nc, aux_g = group_fn(x, gp, gc)
-            aux_total = aux_total + aux_g
+            aux_total = _add_aux(aux_total, aux_g)
             if caches is not None:
                 new_groups.append(nc)
         if caches is not None:

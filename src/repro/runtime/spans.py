@@ -2,7 +2,9 @@
 
 Device scopes (``jax.named_scope``) tag the HLO ``op_name`` metadata of the
 ops they cover; a profile of a TPU run carries it as each op's ``tf_op``.
-No scope nests inside another, so every scoped op belongs to exactly one:
+Only the two MoE scopes nest, and only inside ``dude.backward``; every
+other scoped op belongs to exactly one scope, and the first ``dude.*`` name
+in an op name is that of its layer:
 
 * ``dude.unravel``  — flat ``[P]`` params (or a snapshot) -> model pytree;
 * ``dude.backward`` — one worker's (or the vmapped workers') forward and
@@ -11,7 +13,11 @@ No scope nests inside another, so every scoped op belongs to exactly one:
   with its sharding constraints and the data-axis reduce-scatter;
 * ``dude.round``    — the round rule and its fused (or gated) apply;
 * ``dude.commit`` / ``dude.apply`` — one arrival's server rule and its flat
-  optimizer apply.
+  optimizer apply;
+* inside ``dude.backward``, in an MoE layer (``models/moe.py``):
+  ``dude.moe_route`` — the router, top-k, the sort, the gathers of the
+  dispatch and the combine — and ``dude.moe_experts`` — the grouped matmuls
+  over the held experts and their activation.
 
 Host spans (``jax.profiler.TraceAnnotation``, via :func:`span`) mark what
 the host is doing, on the profiler's clock; their keyword ids are encoded
@@ -39,7 +45,10 @@ RAVEL = "dude.ravel"
 ROUND = "dude.round"
 COMMIT = "dude.commit"      # a device scope and a host span
 APPLY = "dude.apply"
-SCOPES = (UNRAVEL, BACKWARD, RAVEL, ROUND, COMMIT, APPLY)
+MOE_ROUTE = "dude.moe_route"        # nested in BACKWARD
+MOE_EXPERTS = "dude.moe_experts"    # nested in BACKWARD
+SCOPES = (UNRAVEL, BACKWARD, RAVEL, ROUND, COMMIT, APPLY, MOE_ROUTE,
+          MOE_EXPERTS)
 
 STEP = "dude.step"
 ARRIVAL = "dude.arrival"

@@ -24,7 +24,7 @@ CASES = {
     "dense": mk("dense", qkv_bias=True, qk_norm=True),
     "swa": mk("swa", sliding_window=8),
     "moe": mk("moe", arch_type="moe", block_pattern=("moe",), num_experts=4,
-              experts_per_tok=2, moe_d_ff=64, capacity_factor=8.0),
+              experts_per_tok=2, moe_d_ff=64),
     "xlstm": mk("xlstm", arch_type="ssm", block_pattern=("mlstm", "slstm"),
                 ssm_state=16),
     "zamba": mk("zamba", arch_type="hybrid",
