@@ -2,14 +2,24 @@
 projection), QKV bias, sliding window, KV caches, and memory-bounded chunked
 softmax.
 
-Three execution paths:
+Four execution paths:
   * ``attention_ref``      — naive O(S^2) materialized scores (tests/oracles).
+  * ``causal_attention``   — (``kernels/splash_attention.py``) the Pallas
+                             splash kernels: a causal block-sparse forward
+                             and dq / dkv backward kernels on bf16 operands.
+                             The training / prefill path on the TPU.
   * ``attention_chunked``  — online-softmax over KV chunks via ``lax.scan``;
                              mathematically identical, O(S * chunk) memory.
-                             This is the default training/prefill path and the
-                             jnp counterpart of the Pallas flash kernel.
+                             The training / prefill path everywhere else.
   * ``decode_attend``      — single-token attention against a cache
                              (flash-decode math; optional sliding window).
+
+``attention_apply`` picks the kernel from what it can observe
+(:func:`uses_flash_kernel`): the backend is the TPU, no KV cache and no
+sliding window is in play, the activations are on no mesh of more than
+one device (the shard hook's ``mesh``: a custom call is not partitioned),
+and S is a multiple of 128.  The attention core of every path runs under
+the ``dude.attention`` device scope.
 """
 
 from __future__ import annotations
@@ -20,6 +30,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from ..kernels.splash_attention import LANES, causal_attention
+from ..runtime import spans
 from .layers import apply_rope, dense, dense_init, rmsnorm, rmsnorm_init
 
 Pytree = Any
@@ -238,6 +250,13 @@ def decode_attend(q, cache, length, *, window: Optional[int] = None):
 
 # ----------------------------------------------------------- full attn module
 
+def uses_flash_kernel(*, backend: str, cached: bool, window: Optional[int],
+                      sharded: bool, seq_len: int) -> bool:
+    """Whether attention runs the Pallas kernel rather than the jnp scan."""
+    return (backend == "tpu" and not cached and window is None
+            and not sharded and seq_len % LANES == 0)
+
+
 def attention_apply(
     p: Pytree,
     x: jnp.ndarray,
@@ -252,16 +271,23 @@ def attention_apply(
     """Self-attention block body.  Returns (out, new_cache)."""
     window = cfg.sliding_window if use_window else None
     q, k, v = _project_qkv(p, x, positions, cfg, shard)
-    if cache is None:
-        out = attention_chunked(q, k, v, causal=True, window=window,
-                                chunk=cfg.chunk)
-        new_cache = None
-    else:
-        cache = update_cache(cache, k, v, cache_index)
-        length = cache_index + x.shape[1]
-        out = decode_attend(q, cache, length, window=window)
-        new_cache = cache
     B, S = x.shape[:2]
+    mesh = getattr(shard, "mesh", None)
+    flash = uses_flash_kernel(backend=jax.default_backend(),
+                              cached=cache is not None, window=window,
+                              sharded=mesh is not None and mesh.size > 1,
+                              seq_len=S)
+    new_cache = None
+    if cache is not None:
+        new_cache = update_cache(cache, k, v, cache_index)
+    with jax.named_scope(spans.ATTENTION):
+        if flash:
+            out = causal_attention(q, k, v)
+        elif cache is None:
+            out = attention_chunked(q, k, v, causal=True, window=window,
+                                    chunk=cfg.chunk)
+        else:
+            out = decode_attend(q, new_cache, cache_index + S, window=window)
     out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
     out = dense(p["wo"], out)
     return shard(out, "act_resid"), new_cache

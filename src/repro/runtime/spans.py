@@ -2,9 +2,10 @@
 
 Device scopes (``jax.named_scope``) tag the HLO ``op_name`` metadata of the
 ops they cover; a profile of a TPU run carries it as each op's ``tf_op``.
-Only the two MoE scopes nest, and only inside ``dude.backward``; every
-other scoped op belongs to exactly one scope, and the first ``dude.*`` name
-in an op name is that of its layer:
+Only the attention scope and the two MoE scopes nest, and only inside
+``dude.backward`` (they tag the model's ops, which a serving path runs
+under no layer scope); every other scoped op belongs to exactly one scope,
+and the first ``dude.*`` name in an op name is that of its layer:
 
 * ``dude.unravel``  — flat ``[P]`` params (or a snapshot) -> model pytree;
 * ``dude.backward`` — one worker's (or the vmapped workers') forward and
@@ -14,6 +15,10 @@ in an op name is that of its layer:
 * ``dude.round``    — the round rule and its fused (or gated) apply;
 * ``dude.commit`` / ``dude.apply`` — one arrival's server rule and its flat
   optimizer apply;
+* inside ``dude.backward``, in every attention layer
+  (``models/attention.py``): ``dude.attention`` — the attention core (the
+  Pallas kernels or the jnp scan, or a decode step's attention over its
+  cache), not the projections;
 * inside ``dude.backward``, in an MoE layer (``models/moe.py``):
   ``dude.moe_route`` — the router, top-k, the sort, the gathers of the
   dispatch and the combine — and ``dude.moe_experts`` — the grouped matmuls
@@ -45,10 +50,11 @@ RAVEL = "dude.ravel"
 ROUND = "dude.round"
 COMMIT = "dude.commit"      # a device scope and a host span
 APPLY = "dude.apply"
+ATTENTION = "dude.attention"        # nested in BACKWARD
 MOE_ROUTE = "dude.moe_route"        # nested in BACKWARD
 MOE_EXPERTS = "dude.moe_experts"    # nested in BACKWARD
-SCOPES = (UNRAVEL, BACKWARD, RAVEL, ROUND, COMMIT, APPLY, MOE_ROUTE,
-          MOE_EXPERTS)
+SCOPES = (UNRAVEL, BACKWARD, RAVEL, ROUND, COMMIT, APPLY, ATTENTION,
+          MOE_ROUTE, MOE_EXPERTS)
 
 STEP = "dude.step"
 ARRIVAL = "dude.arrival"

@@ -631,4 +631,5 @@ def make_shard_hook(mesh: Optional[Mesh]):
             fitted.append(ax if dim % _axsize(mesh, ax) == 0 else None)
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*fitted)))
 
+    hook.mesh = mesh    # the model reads where its activations live
     return hook

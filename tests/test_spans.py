@@ -1,10 +1,12 @@
 """The program's profiler names (``runtime/spans.py``).
 
 * Device scopes: in the debug info of the lowered round step, every
-  ``dot_general`` sits under exactly one ``dude.*`` scope, ``dude.backward``,
-  and the fused round kernel's call under ``dude.round`` (lowered for the
-  TPU, nothing compiled); the arrival path's jits put their ops under the
-  scope of their layer.  A private function's ops take the scopes of its
+  ``dot_general`` sits under one layer scope, ``dude.backward``, those of
+  the attention core under ``dude.attention`` nested in it, and the fused
+  round kernel's call under ``dude.round`` (lowered for the TPU, nothing
+  compiled); on the TPU path the attention kernels' calls sit under
+  ``dude.attention`` in ``dude.backward``; the arrival path's jits put their
+  ops under the scope of their layer.  A private function's ops take the scopes of its
   call sites, as XLA's ``op_name`` does when it inlines the call.
 * Host spans: a small ``run_async`` under ``jax.profiler.trace`` has one
   ``dude.arrival`` span per arrival carrying its ids, the children of each
@@ -29,7 +31,9 @@ from repro.runtime import spans
 
 N = 2
 S = 16
-SCOPE = re.compile(r"(?<=/)(dude\.[a-z_]+)(?=/)")
+# a scope in an op's location: after a '/', or first in a private
+# function's (relative) name stack
+SCOPE = re.compile(r"(?<=[/\"])(dude\.[a-z_]+)(?=/)")
 # ops that compute nothing: their place in a scope does not matter
 STRUCTURAL = {"func.return", "func.call", "stablehlo.return",
               "stablehlo.constant", "stablehlo.optimization_barrier",
@@ -90,27 +94,59 @@ def _batch(cfg, key=0):
     return {"tokens": toks, "labels": toks}
 
 
-def test_round_step_scopes(trainer):
-    """The round step lowered for the TPU (the Mosaic kernel, not its CPU
-    interpreter): every matmul is the backward's, the kernel is the
-    round's, and no op carries two scopes."""
+def _lowered_round_step(trainer, seq_len=S):
+    """The round step lowered for the TPU (the Mosaic kernels, not their
+    CPU interpreter)."""
     eng = dataclasses.replace(trainer.engine, backend="pallas",
                               interpret=False)
     step = make_train_step(trainer.cfg, None, trainer.opt, trainer.dude_cfg,
                            options=trainer.options, engine=eng,
                            algo=trainer.algo)
     m = jnp.ones((N,), bool)
-    lowered = jax.jit(step).trace(trainer.state, _batch(trainer.cfg), m,
-                                  m).lower(lowering_platforms=("tpu",))
-    ops = scoped_ops(lowered)
+    toks = jnp.zeros((N, 1, seq_len), jnp.int32)
+    return jax.jit(step).trace(trainer.state, {"tokens": toks,
+                                               "labels": toks}, m,
+                               m).lower(lowering_platforms=("tpu",))
+
+
+def _kernels(ops) -> list:
+    return [sc for name, sc, o in ops if name == "stablehlo.custom_call"
+            and "tpu_custom_call" in str(o.attributes["call_target_name"])]
+
+
+def _layer_scopes(sc) -> frozenset:
+    return sc - {spans.ATTENTION}
+
+
+def test_round_step_scopes(trainer):
+    """Every matmul is the backward's, those of the attention core under
+    ``dude.attention`` too, the kernel is the round's, and no op carries two
+    layer scopes."""
+    ops = scoped_ops(_lowered_round_step(trainer))
     dots = [sc for name, sc, _ in ops if name == "stablehlo.dot_general"]
-    assert dots and all(sc == {spans.BACKWARD} for sc in dots)
-    kernels = [sc for name, sc, o in ops if name == "stablehlo.custom_call"
-               and "tpu_custom_call" in str(o.attributes["call_target_name"])]
-    assert kernels == [frozenset({spans.ROUND})]
-    assert all(len(sc) <= 1 for _, sc, _ in ops)
+    assert dots and all(_layer_scopes(sc) == {spans.BACKWARD} for sc in dots)
+    assert {spans.BACKWARD, spans.ATTENTION} in dots
+    assert _kernels(ops) == [frozenset({spans.ROUND})]
+    assert all(len(_layer_scopes(sc)) <= 1 for _, sc, _ in ops)
     seen = set().union(*(sc for _, sc, _ in ops))
-    assert seen == {spans.UNRAVEL, spans.BACKWARD, spans.RAVEL, spans.ROUND}
+    assert seen == {spans.UNRAVEL, spans.BACKWARD, spans.RAVEL, spans.ROUND,
+                    spans.ATTENTION}
+
+
+def test_round_step_attention_kernel_scopes(trainer, monkeypatch):
+    """On the TPU path (the backend steered here; S a multiple of 128) the
+    attention kernels' calls, forward and backward, sit under
+    ``dude.attention`` in ``dude.backward``, and the round kernel alone
+    under ``dude.round``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ops = scoped_ops(_lowered_round_step(trainer, seq_len=128))
+    kernels = _kernels(ops)
+    attn = [sc for sc in kernels if sc != {spans.ROUND}]
+    assert kernels.count(frozenset({spans.ROUND})) == 1
+    # forward, the remat's forward, dkv and dq, in each of two layers (one
+    # scanned body)
+    assert len(attn) >= 3
+    assert all(sc == {spans.BACKWARD, spans.ATTENTION} for sc in attn)
 
 
 @pytest.mark.parametrize("commit_format", ["f32", "int8_ef"])
@@ -140,8 +176,8 @@ def test_arrival_jit_scopes(commit_format):
             # the remat'd forward's constant tables (RoPE, masks) are
             # hoisted out of every scope; the matmuls are not
             dots = [sc for n, sc in ops if n == "stablehlo.dot_general"]
-            assert dots and all(sc == {scope} for sc in dots)
-            assert all(len(sc) <= 1 for _, sc in ops)
+            assert dots and all(_layer_scopes(sc) == {scope} for sc in dots)
+            assert all(len(_layer_scopes(sc)) <= 1 for _, sc in ops)
         else:
             assert ops and all(sc == {scope} for _, sc in ops), scope
     gflat = r._ravel(params)

@@ -1,4 +1,5 @@
-"""The fused round kernels compile for a described TPU v5e chip.
+"""The fused round kernels and the training attention kernels compile for
+a described TPU v5e chip.
 
 Nothing runs: each test lowers one kernel at shapes only, with the tile the
 engine derives (``DuDeEngine.tile`` off interpret mode), compiles it with
@@ -22,6 +23,7 @@ from repro.kernels.dude_update import (
     SLOT_STREAMS, dude_round_apply_pallas, dude_round_apply_q_pallas,
     dude_round_apply_sparse_pallas,
 )
+from repro.kernels.splash_attention import causal_attention
 
 # a multiple of the 128-lane pad that no derived tile divides: the last
 # block of every grid is ragged
@@ -143,3 +145,29 @@ def test_round_apply_sparse_compiles_for_v5e(one_chip, n, dtype):
         sds((n, t), jnp.float32), sds((n, t), jnp.int8),
         sds((P,), jnp.float32), sds((P,), jnp.float32)]
     assert "tpu_custom_call" in _compile(f, args, one_chip)
+
+
+@pytest.mark.parametrize("n,seq,heads,kv_heads,hd", [
+    (4, 4096, 16, 16, 128),     # olmoe-1b-7b.round.n4.s4096
+    (8, 1024, 14, 2, 64),       # qwen2-0.5b.round.n8
+    (2, 1024, 16, 8, 128),      # qwen3-1.7b.round.n2
+])
+def test_causal_attention_compiles_for_v5e(one_chip, n, seq, heads,
+                                           kv_heads, hd):
+    """The training attention's forward and backward kernels, vmapped over
+    n workers of one sequence each, at the chip cells' widths."""
+    sds = jax.ShapeDtypeStruct
+
+    def f(q, k, v, cot):
+        def loss(q, k, v):
+            out = jax.vmap(causal_attention)(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * cot)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    args = [sds((n, 1, seq, heads, hd), jnp.bfloat16),
+            sds((n, 1, seq, kv_heads, hd), jnp.bfloat16),
+            sds((n, 1, seq, kv_heads, hd), jnp.bfloat16),
+            sds((n, 1, seq, heads, hd), jnp.float32)]
+    text = _compile(f, args, one_chip)
+    # the forward, dkv and dq kernels
+    assert text.count("tpu_custom_call") >= 3
